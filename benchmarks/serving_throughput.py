@@ -139,8 +139,9 @@ if _MESH_SHAPE is not None:
 import jax
 import numpy as np
 
-from repro import compat
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import transformer
 from repro.serving import FaultInjector, Request, ServingEngine
 
@@ -464,6 +465,7 @@ def main():
     ap.add_argument("--json", type=str, default=None,
                     help="write results to this JSON file")
     args = ap.parse_args()
+    enable_compile_cache()
 
     mesh = None
     if args.mesh:
@@ -474,7 +476,7 @@ def main():
             ap.error(f"--mesh {dd},{mm} needs {dd * mm} devices, have "
                      f"{jax.device_count()} (is XLA_FLAGS overriding "
                      "the forced host device count?)")
-        mesh = compat.make_mesh((dd, mm), ("data", "model"))
+        mesh = make_mesh((dd, mm), ("data", "model"))
 
     cfg = get_config("bitnet-0.73b").reduced(
         n_layers=2, d_model=128, n_heads=4, d_ff=256, vocab_size=256)

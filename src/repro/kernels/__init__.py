@@ -5,8 +5,11 @@ Each kernel package contains:
   ops.py    — jit'd public wrapper (padding, dtype plumbing, interpret switch)
   ref.py    — pure-jnp oracle used by tests and by the XLA fallback paths
 
-On this CPU container kernels run with interpret=True; on TPU the same code
-lowers to Mosaic.  ``default_interpret()`` picks automatically.
+Off the TPU (the CPU test runs) kernels run with interpret=True; on TPU the
+same code lowers to Mosaic.  ``default_interpret()`` picks automatically.
+Interpret mode accepts block shapes Mosaic refuses, so
+``tests/test_tpu_compile.py`` compiles every served kernel for a described
+v5e as well.
 """
 
 import jax

@@ -290,7 +290,6 @@ def decode_attention_splitk_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
     cache reuse) — the serving engine plumbs the same partials/combine
     through its own shard_map'd decode block instead.
     """
-    from repro import compat
     ax = int(mesh.shape[axis_name])
     n_splits = int(num_splits) if num_splits else max(ax, 1)
     validate_num_splits(n_splits, ax, axis_name=axis_name)
@@ -318,6 +317,6 @@ def decode_attention_splitk_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
         return splitk_combine(m, l, acc, q.dtype)
 
     reps = tuple(P() for _ in range(4))
-    fn = compat.shard_map(body, mesh=mesh, in_specs=reps, out_specs=P(),
-                          check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=reps, out_specs=P(),
+                       check_vma=False)
     return jax.jit(fn)(q, k, v, jnp.asarray(cache_len))

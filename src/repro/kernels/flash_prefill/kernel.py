@@ -150,7 +150,7 @@ def _chunk_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
 
 def _paged_chunk_kernel(bt_ref, off_ref, q_ref, kp_ref, vp_ref, kf_ref,
                         vf_ref, o_ref, acc_ref, m_ref, l_ref, *, scale: float,
-                        bq: int, page_size: int, n_pages: int,
+                        bq: int, page_size: int, n_pages: int, group: int,
                         window: int | None):
     """Paged chunked-prefill attention.  The kv grid axis is split in two
     logical phases: steps ``ki < n_pages`` stream the slot's already-written
@@ -159,10 +159,17 @@ def _paged_chunk_kernel(bt_ref, off_ref, q_ref, kp_ref, vp_ref, kf_ref,
     pages are fetched), and steps ``ki >= n_pages`` walk the chunk's own
     fresh K/V tiles (full-precision operands, matching the contiguous path's
     fresh-chunk overlay) under the causal triangle.  Pool pages at or beyond
-    the prefix — and fresh tiles above the diagonal — issue no MXU work."""
+    the prefix — and fresh tiles above the diagonal — issue no MXU work.
+
+    Each step covers every head: the pool block is a whole page
+    (1, page_size, kv_h, d) — a one-head block would end in (1, d), which
+    Mosaic refuses — so a page is fetched once per (slot, q tile), and the
+    body loops over the query heads (head ``hq`` reads kv head
+    ``hq // group``)."""
     bi = pl.program_id(0)
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+    n_heads = q_ref.shape[1]
 
     @pl.when(ki == 0)
     def _init():
@@ -173,10 +180,11 @@ def _paged_chunk_kernel(bt_ref, off_ref, q_ref, kp_ref, vp_ref, kf_ref,
     offset = off_ref[bi]
     q_start = offset + qi * bq  # absolute position of this q tile's first row
 
-    def online_update(k, v, k_ids, extra_mask):
-        """Shared online-softmax step.  k, v: (tile, d) f32; k_ids: (1, tile)
-        absolute key positions; extra_mask: (bq, tile) or scalar True."""
-        q = q_ref[0, 0].astype(jnp.float32)                    # (bq, d)
+    def online_update(hq, k, v, k_ids, extra_mask):
+        """Shared online-softmax step for query head ``hq``.  k, v:
+        (tile, d) f32; k_ids: (1, tile) absolute key positions; extra_mask:
+        (bq, tile) or scalar True."""
+        q = q_ref[0, hq].astype(jnp.float32)                   # (bq, d)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         q_ids = q_start + jax.lax.broadcasted_iota(
@@ -185,15 +193,15 @@ def _paged_chunk_kernel(bt_ref, off_ref, q_ref, kp_ref, vp_ref, kf_ref,
         if window is not None:
             mask = jnp.logical_and(mask, k_ids > q_ids - window)
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
+        m_prev = m_ref[hq]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        l_ref[hq] = l_ref[hq] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[hq] = acc_ref[hq] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        m_ref[hq] = m_new
 
     # -- phase 1: pool pages holding the [0, offset) prefix ------------------
     k_start_pool = ki * page_size
@@ -204,13 +212,15 @@ def _paged_chunk_kernel(bt_ref, off_ref, q_ref, kp_ref, vp_ref, kf_ref,
 
     @pl.when(run_pool)
     def _pool():
-        k = kp_ref[0, :, 0].astype(jnp.float32)       # (page_size, d)
-        v = vp_ref[0, :, 0].astype(jnp.float32)
         k_ids = k_start_pool + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1)
-        # prefix-only: positions >= offset live in the fresh operand (or are
-        # stale page slack) and must not be read from the pool
-        online_update(k, v, k_ids, k_ids < offset)
+        for hq in range(n_heads):
+            g = hq // group
+            k = kp_ref[0, :, g, :].astype(jnp.float32)    # (page_size, d)
+            v = vp_ref[0, :, g, :].astype(jnp.float32)
+            # prefix-only: positions >= offset live in the fresh operand (or
+            # are stale page slack) and must not be read from the pool
+            online_update(hq, k, v, k_ids, k_ids < offset)
 
     # -- phase 2: the chunk's own fresh K/V tiles (causal triangle) ----------
     fi = ki - n_pages
@@ -221,16 +231,18 @@ def _paged_chunk_kernel(bt_ref, off_ref, q_ref, kp_ref, vp_ref, kf_ref,
 
     @pl.when(run_fresh)
     def _fresh():
-        k = kf_ref[0, 0].astype(jnp.float32)          # (bq, d)
-        v = vf_ref[0, 0].astype(jnp.float32)
         k_ids = offset + fi * bq + jax.lax.broadcasted_iota(
             jnp.int32, (1, bq), 1)
-        online_update(k, v, k_ids, True)
+        for hq in range(n_heads):
+            g = hq // group
+            k = kf_ref[0, g].astype(jnp.float32)          # (bq, d)
+            v = vf_ref[0, g].astype(jnp.float32)
+            online_update(hq, k, v, k_ids, True)
 
-    @pl.when(ki == pl.num_programs(3) - 1)
+    @pl.when(ki == pl.num_programs(2) - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def flash_chunk_prefill_paged_pallas(q: jax.Array, k_pool: jax.Array,
@@ -249,38 +261,35 @@ def flash_chunk_prefill_paged_pallas(q: jax.Array, k_pool: jax.Array,
     page_size, kv_h = k_pool.shape[1], k_pool.shape[2]
     n_pages = block_tables.shape[1]
     assert h % kv_h == 0 and t % bq == 0
-    group = h // kv_h
     nf = t // bq
-    grid = (b, h, t // bq, n_pages + nf)
+    grid = (b, t // bq, n_pages + nf)
 
-    def pool_idx(bi, hi, qi, ki, bt_ref, off_ref):
+    def pool_idx(bi, qi, ki, bt_ref, off_ref):
         # fresh-phase steps clamp to a valid page so the (unused) DMA target
         # stays in bounds
-        return (bt_ref[bi, jnp.minimum(ki, n_pages - 1)], 0, hi // group, 0)
+        return (bt_ref[bi, jnp.minimum(ki, n_pages - 1)], 0, 0, 0)
 
-    def fresh_idx(bi, hi, qi, ki, bt_ref, off_ref):
-        return (bi, hi // group,
-                jnp.clip(ki - n_pages, 0, nf - 1), 0)
+    def fresh_idx(bi, qi, ki, bt_ref, off_ref):
+        return (bi, 0, jnp.clip(ki - n_pages, 0, nf - 1), 0)
+
+    def q_idx(bi, qi, ki, bt_ref, off_ref):
+        return (bi, 0, qi, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block tables + offsets
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, bq, d),
-                         lambda bi, hi, qi, ki, bt_ref, off_ref:
-                         (bi, hi, qi, 0)),
-            pl.BlockSpec((1, page_size, 1, d), pool_idx),
-            pl.BlockSpec((1, page_size, 1, d), pool_idx),
-            pl.BlockSpec((1, 1, bq, d), fresh_idx),
-            pl.BlockSpec((1, 1, bq, d), fresh_idx),
+            pl.BlockSpec((1, h, bq, d), q_idx),
+            pl.BlockSpec((1, page_size, kv_h, d), pool_idx),
+            pl.BlockSpec((1, page_size, kv_h, d), pool_idx),
+            pl.BlockSpec((1, kv_h, bq, d), fresh_idx),
+            pl.BlockSpec((1, kv_h, bq, d), fresh_idx),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, d),
-                               lambda bi, hi, qi, ki, bt_ref, off_ref:
-                               (bi, hi, qi, 0)),
+        out_specs=pl.BlockSpec((1, h, bq, d), q_idx),
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((h, bq, d), jnp.float32),
+            pltpu.VMEM((h, bq, 1), jnp.float32),
+            pltpu.VMEM((h, bq, 1), jnp.float32),
         ],
     )
     bt = jnp.asarray(block_tables, jnp.int32)
@@ -288,7 +297,7 @@ def flash_chunk_prefill_paged_pallas(q: jax.Array, k_pool: jax.Array,
     return pl.pallas_call(
         functools.partial(_paged_chunk_kernel, scale=scale, bq=bq,
                           page_size=page_size, n_pages=n_pages,
-                          window=window),
+                          group=h // kv_h, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
         interpret=interpret,

@@ -27,7 +27,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro.compat import cost_analysis_dict
 from repro.configs import ARCHS, PAPER_ARCH, SHAPES, get_config, shape_applicable
 from repro.data.pipeline import make_batch_specs
 from repro.launch.mesh import make_production_mesh
@@ -248,7 +247,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
             ma = compiled.memory_analysis()
-            ca = cost_analysis_dict(compiled)
+            ca = compiled.cost_analysis() or {}
             coll = collective_bytes(compiled.as_text())
         result.update({
             "ok": True,

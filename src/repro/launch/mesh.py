@@ -1,15 +1,27 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-A FUNCTION, not a module-level constant, so importing this module never
+FUNCTIONS, not module-level constants, so importing this module never
 touches jax device state (device count locks on first jax init).
 
-Meshes are built through ``repro.compat.make_mesh`` so the same code runs on
-JAX versions with and without ``jax.sharding.AxisType``.
+Every axis is ``AxisType.Auto``: the serving engine and the sharding rules
+in ``runtime/sharding.py`` are written for GSPMD propagation, while
+``jax.make_mesh`` defaults to explicit axes.
 """
 
 from __future__ import annotations
 
-from repro.compat import make_mesh
+from typing import Optional, Sequence
+
+import jax
+
+
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
+              devices: Optional[Sequence] = None):
+    """``jax.make_mesh`` with every axis Auto (see the module docstring)."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -15,6 +15,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer
 from repro.serving import Request, ServingEngine
 
@@ -32,6 +33,7 @@ def main():
     ap.add_argument("--batch-slots", type=int, default=4)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def pipeline_forward(stage_fn: Callable, mesh: Mesh, axis: str,
                      stage_params, x_micro):
@@ -82,7 +80,7 @@ def pipeline_forward(stage_fn: Callable, mesh: Mesh, axis: str,
     n_axes = len(mesh.axis_names)
     stage_spec = jax.tree_util.tree_map(
         lambda p: P(*((axis,) + (None,) * (p.ndim - 1))), stage_params)
-    return shard_map(
+    return jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(stage_spec, P()),
         out_specs=P(),

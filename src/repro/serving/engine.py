@@ -331,9 +331,8 @@ from typing import Callable, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.kernels.decode_attention.ops import validate_num_splits
 from repro.models import transformer
@@ -1069,7 +1068,7 @@ class ServingEngine:
             st, cs, bts = specs["state"], specs["cache"], specs["bt"]
             blks, toks = specs["blk"], specs["tokens"]
             rep = P()
-            smap = functools.partial(compat.shard_map, mesh=mesh,
+            smap = functools.partial(jax.shard_map, mesh=mesh,
                                      check_vma=False)
             self._prefill_chunks = jax.jit(smap(
                 _prefill_chunks,
@@ -1088,6 +1087,10 @@ class ServingEngine:
                 donate_argnums=(0,))
             self._shardings = shardlib.serving_shardings(
                 mesh, {"state": st, "bt": bts, "cache": cs})
+            # replicate the weights over the mesh once: left on one device
+            # they would be re-broadcast to every shard by each dispatch
+            self.params = jax.device_put(packed_params,
+                                         NamedSharding(mesh, rep))
         self._admit_lanes = _admit_lanes
         self._set_bt_row = _set_bt_row
         self._kill_lane = _kill_lane

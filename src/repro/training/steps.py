@@ -90,8 +90,6 @@ def make_train_step_ddp(cfg: ModelConfig, ctx: Ctx, optimizer: Optimizer,
 
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     # inside shard_map every axis is manual: sharding constraints are
     # meaningless (and rejected) — drop the hook for the per-shard body
     ctx = dataclasses.replace(ctx, constrain=None)
@@ -135,7 +133,7 @@ def make_train_step_ddp(cfg: ModelConfig, ctx: Ctx, optimizer: Optimizer,
         return jax.tree_util.tree_map(lambda _: rep, tree)
 
     def train_step(params, opt_state, err, batch):
-        return shard_map(
+        return jax.shard_map(
             per_shard, mesh=mesh,
             in_specs=(spec_like(params), spec_like(opt_state),
                       spec_like(err), batch_spec),

@@ -31,7 +31,7 @@ import sys
 import jax
 import pytest
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(ROOT, "src")
@@ -57,7 +57,7 @@ def _run_sub(script: str, sentinel: str, devices: int = 4,
 _PROLOGUE = """
 import jax
 import numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.models import transformer
 from repro.models.layers import Ctx
@@ -107,7 +107,7 @@ for mode in MODES:
                                 **mode)
         assert base == base_kv, (mode, dev, "kv_splits single-device")
         for shape in ((1, 1), (2, 1), (1, 2), (2, 2)):
-            mesh = compat.make_mesh(shape, ("data", "model"))
+            mesh = make_mesh(shape, ("data", "model"))
             out, eng = run_engine(PROMPTS, device_sched=dev, mesh=mesh,
                                   shard_kv=shape[1] > 1, **mode)
             assert out == base, (mode, dev, shape, out, base)
@@ -131,7 +131,7 @@ def test_mesh_nondivisible_slots_and_kv():
 prompts = PROMPTS + [np.asarray([5, 5, 5], np.int32)]
 base, _ = run_engine(prompts, max_new=8, max_seq=31, batch_slots=3,
                      kv_splits=2)
-mesh = compat.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 out, eng = run_engine(prompts, max_new=8, max_seq=31, batch_slots=3,
                       mesh=mesh, shard_kv=True)
 assert eng.slots == 4 and eng.requested_slots == 3, eng.slots
@@ -165,7 +165,7 @@ kw = dict(batch_slots=4, paged=True, page_size=4, kv_pages=64,
 
 base, beng = run_engine(prompts, max_new=6, temps=False, **kw)
 assert beng.stats["kv_cow_splits"] > 0  # the fixture really exercises CoW
-mesh = compat.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 out, eng = run_engine(prompts, max_new=6, temps=False, mesh=mesh,
                       shard_kv=True, **kw)
 assert out == base, (out, base)
@@ -192,7 +192,7 @@ def test_mesh_splitk_combine_bitwise_real_mesh():
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.kernels.decode_attention import ops as da_ops
 
 for s in (257, 256, 101, 31):
@@ -205,7 +205,7 @@ for s in (257, 256, 101, 31):
         for K in (mm, 2 * mm):
             ref = da_ops.decode_attention_splitk(q, k, v, clen,
                                                  num_splits=K)
-            mesh = compat.make_mesh((mm,), ("model",))
+            mesh = make_mesh((mm,), ("model",))
             out = da_ops.decode_attention_splitk_sharded(
                 q, k, v, clen, mesh=mesh, num_splits=K)
             assert np.array_equal(np.asarray(out), np.asarray(ref)), \\
@@ -223,7 +223,7 @@ def test_mesh_smoke_2x2():
 kw = dict(paged=True, page_size=4, kv_pages=40,
           enable_prefix_sharing=True)
 base, _ = run_engine(PROMPTS, **kw)
-mesh = compat.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 out, eng = run_engine(PROMPTS, mesh=mesh, shard_kv=True, **kw)
 assert out == base, (out, base)
 assert eng.stats["steady_state_syncs_per_block"] == 0.0
@@ -264,7 +264,7 @@ brs = reqs(prompts())
 beng.run(brs)
 baseline = [r.output.tolist() for r in brs]
 
-mesh = compat.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 eng = ServingEngine(cfg, packed, ctx=ctx, prefill_chunk=4, decode_block=4,
                     mesh=mesh, shard_kv=True, **KW, **REC)
 healed = retried = promoted = 0
@@ -303,15 +303,15 @@ def test_mesh_validation_errors():
     cfg = get_config("qwen1.5-0.5b").reduced()
     packed = transformer.pack_params(
         cfg, transformer.init_params(cfg, jax.random.PRNGKey(0)))
-    bad = compat.make_mesh((1, 1), ("x", "model"))
+    bad = make_mesh((1, 1), ("x", "model"))
     with pytest.raises(ValueError, match="axis_names"):
         ServingEngine(cfg, packed, max_seq=16, mesh=bad)
     with pytest.raises(ValueError, match="kv_splits"):
         ServingEngine(cfg, packed, max_seq=16,
-                      mesh=compat.make_mesh((1, 1), ("data", "model")),
+                      mesh=make_mesh((1, 1), ("data", "model")),
                       kv_splits=0)
     # a (1, 1) mesh engine is exactly the single-device engine's semantics
     eng = ServingEngine(cfg, packed, max_seq=16,
-                        mesh=compat.make_mesh((1, 1), ("data", "model")))
+                        mesh=make_mesh((1, 1), ("data", "model")))
     assert eng.mesh_shape == (1, 1) and not eng.shard_slots \
         and not eng.shard_kv

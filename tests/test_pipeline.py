@@ -18,7 +18,7 @@ def test_gpipe_matches_sequential_on_4_devices():
         from repro.runtime.pipeline import (pipeline_forward,
                                             split_layers_into_stages)
 
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         S, L, D = 4, 8, 16
         mesh = make_mesh((S,), ("pod",))
         key = jax.random.PRNGKey(0)
@@ -50,7 +50,7 @@ def test_gpipe_matches_sequential_on_4_devices():
                                    atol=1e-5, rtol=1e-5)
         print("GPIPE_OK")
     """)
-    env = dict(os.environ, PYTHONPATH="src")
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", script], capture_output=True,
                          text=True, env=env, cwd=os.getcwd(), timeout=600)
     assert out.returncode == 0, out.stdout + out.stderr

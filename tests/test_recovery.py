@@ -493,7 +493,7 @@ def test_transient_schedules_recover_property(served_model, baselines,
 _MESH_HEAL_SCRIPT = """
 import jax
 import numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.models import transformer
 from repro.models.layers import Ctx
@@ -522,7 +522,7 @@ brs = reqs()
 beng.run(brs)
 baseline = [r.output.tolist() for r in brs]
 
-mesh = compat.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 eng = ServingEngine(cfg, packed, ctx=ctx, mesh=mesh, shard_kv=True,
                     max_retries=4, retry_backoff_s=0.0,
                     retry_breaker_threshold=99, probe_cooldown_blocks=1,

@@ -95,7 +95,7 @@ def test_compression_error_shrinks_with_feedback():
 def test_compressed_psum_single_device():
     """shard_map psum path on a 1-device mesh (degenerate reduction)."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.compat import make_mesh, shard_map
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((1,), ("data",))
     g = jnp.linspace(-1, 1, 32)
     err = jnp.zeros_like(g)
@@ -103,8 +103,8 @@ def test_compressed_psum_single_device():
     def f(g, err):
         return compression.compressed_psum(g, err, "data")
 
-    out, new_err = shard_map(f, mesh=mesh, in_specs=(P(), P()),
-                             out_specs=(P(), P()))(g, err)
+    out, new_err = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                                 out_specs=(P(), P()))(g, err)
     np.testing.assert_allclose(np.asarray(out), np.asarray(g), atol=0.02)
 
 
@@ -171,7 +171,7 @@ def test_checkpoint_elastic_reshard(tmp_path):
     mgr = CheckpointManager(str(tmp_path))
     tree = {"w": jnp.arange(16.0).reshape(4, 4)}
     mgr.save(1, tree, blocking=True)
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((1,), ("model",))
     sh = {"w": NamedSharding(mesh, P("model", None))}
     restored = mgr.restore(1, tree, shardings=sh)

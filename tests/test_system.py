@@ -66,7 +66,7 @@ def test_multi_device_sharded_train_executes():
         from repro.runtime import sharding as shd
         from repro.training import make_train_step
 
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_config("granite-3-2b").reduced(
             n_layers=2, d_model=64, n_heads=4, n_kv_heads=4,
@@ -87,7 +87,7 @@ def test_multi_device_sharded_train_executes():
         assert loss == loss and loss > 0, loss
         print("MULTIDEV_OK", loss)
     """)
-    env = dict(os.environ, PYTHONPATH="src")
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", script], capture_output=True,
                          text=True, env=env, cwd=os.getcwd(), timeout=600)
     assert out.returncode == 0, out.stdout + out.stderr
@@ -109,7 +109,7 @@ def test_multi_device_compressed_ddp_executes():
         from repro.optim.compression import init_error_state
         from repro.training.steps import make_train_step_ddp
 
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((8,), ("data",))
         cfg = get_config("qwen1.5-0.5b").reduced(
             n_layers=2, d_model=64, n_heads=4, d_ff=128, vocab_size=128)
@@ -128,7 +128,7 @@ def test_multi_device_compressed_ddp_executes():
         assert losses[-1] < losses[0], losses
         print("DDP_OK", losses[0], "->", losses[-1])
     """)
-    env = dict(os.environ, PYTHONPATH="src")
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", script], capture_output=True,
                          text=True, env=env, cwd=os.getcwd(), timeout=600)
     assert out.returncode == 0, out.stdout + out.stderr
