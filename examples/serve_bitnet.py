@@ -42,9 +42,12 @@ wall = time.perf_counter() - t0
 
 total = sum(len(r.output) for r in requests)
 st = engine.stats
+host = st["host_s"]
 print(f"served {len(requests)} requests / {total} new tokens "
-      f"in {wall:.2f}s -> {total/wall:.1f} tok/s aggregate, "
-      f"{st['decode_tok_s']:.1f} tok/s decode-only")
+      f"in {wall:.2f}s -> {total/wall:.1f} tok/s aggregate; host "
+      f"{host['block'] + host['readback']:.2f}s dispatching and reading "
+      f"back decode blocks, longest beat {st['beat_max_s']*1e3:.0f}ms "
+      f"(mostly {st['beat_max_stage']})")
 print(f"decode blocks {st['decode_blocks']} ({st['decode_steps']} fused "
       f"ticks), prefill waves {st['prefill_chunks']}, admissions "
       f"{st['admissions']} ({st['mid_flight_admissions']} mid-flight), "

@@ -9,6 +9,13 @@ runs: it is never built from a temp name, a pid or the time.
   sets no other directory.
 * Otherwise: ``<checkout>/.jax_cache`` (listed in ``.gitignore``).
 
+The key also covers the programs' metadata (op names and their
+``jax.named_scope`` paths, source lines): an entry compiled from code
+whose scopes have since changed would otherwise be found again, and a
+profile of it would show the old scopes.  Source paths enter the
+metadata relative to the checkout, so the key does not depend on where
+the checkout lies.
+
 Entry points call :func:`enable_compile_cache` once, before their first
 compile.  Tests never call it.
 """
@@ -16,6 +23,7 @@ compile.  Tests never call it.
 from __future__ import annotations
 
 import os
+import re
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -35,4 +43,7 @@ def enable_compile_cache() -> str:
     path = cache_dir()
     if not os.environ.get(ENV_VAR):
         jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(CHECKOUT + os.sep))
     return path

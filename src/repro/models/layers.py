@@ -159,14 +159,17 @@ def mlp_init(key, d_model: int, d_ff: int, *, dtype=jnp.float32) -> dict:
 
 
 def mlp_apply(p: dict, x: jax.Array, ctx: Ctx, *, ternary_w=True) -> jax.Array:
-    if "gateup" in p:  # fused projection (pre-decoded serving hot path)
-        gu = linear_apply(p["gateup"], x, ctx, ternary_w=ternary_w)
-        g, u = jnp.split(gu, 2, axis=-1)
-    else:
-        g = linear_apply(p["gate"], x, ctx, ternary_w=ternary_w)
-        u = linear_apply(p["up"], x, ctx, ternary_w=ternary_w)
-    h = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
-    return linear_apply(p["down"], h.astype(x.dtype), ctx, ternary_w=ternary_w)
+    with jax.named_scope("gate_up"):
+        if "gateup" in p:  # fused projection (pre-decoded serving hot path)
+            gu = linear_apply(p["gateup"], x, ctx, ternary_w=ternary_w)
+            g, u = jnp.split(gu, 2, axis=-1)
+        else:
+            g = linear_apply(p["gate"], x, ctx, ternary_w=ternary_w)
+            u = linear_apply(p["up"], x, ctx, ternary_w=ternary_w)
+        h = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+    with jax.named_scope("down"):
+        return linear_apply(p["down"], h.astype(x.dtype), ctx,
+                            ternary_w=ternary_w)
 
 
 def mlp_pack(p: dict, g: int) -> dict:

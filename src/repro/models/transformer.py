@@ -177,7 +177,8 @@ def predecode_packed(cfg: ModelConfig, params: dict) -> dict:
         return out
 
     out = {k: v for k, v in params.items() if k != "layers"}
-    out["layers"] = jax.vmap(layer)(params["layers"])
+    with jax.named_scope("predecode"):
+        out["layers"] = jax.vmap(layer)(params["layers"])
     return out
 
 
@@ -269,17 +270,33 @@ def _attn_apply(cfg: ModelConfig, ctx: Ctx, p: dict, x: jax.Array,
                 chunk_mask=None,
                 page_table=None) -> Tuple[jax.Array, Optional[dict]]:
     b, t, _ = x.shape
-    if "qkv" in p:  # fused projection (pre-decoded serving hot path)
-        qkv = layers.linear_apply(p["qkv"], x, ctx)
-        q, k, v = jnp.split(qkv, [cfg.q_dim, cfg.q_dim + cfg.kv_dim],
-                            axis=-1)
-    else:
-        q = layers.linear_apply(p["q"], x, ctx)
-        k = layers.linear_apply(p["k"], x, ctx)
-        v = layers.linear_apply(p["v"], x, ctx)
-    q = q.reshape(b, t, cfg.n_heads, cfg.hd)
-    k = k.reshape(b, t, cfg.n_kv_heads, cfg.hd)
-    v = v.reshape(b, t, cfg.n_kv_heads, cfg.hd)
+    with jax.named_scope("qkv"):
+        if "qkv" in p:  # fused projection (pre-decoded serving hot path)
+            qkv = layers.linear_apply(p["qkv"], x, ctx)
+            q, k, v = jnp.split(qkv, [cfg.q_dim, cfg.q_dim + cfg.kv_dim],
+                                axis=-1)
+        else:
+            q = layers.linear_apply(p["q"], x, ctx)
+            k = layers.linear_apply(p["k"], x, ctx)
+            v = layers.linear_apply(p["v"], x, ctx)
+        q = q.reshape(b, t, cfg.n_heads, cfg.hd)
+        k = k.reshape(b, t, cfg.n_kv_heads, cfg.hd)
+        v = v.reshape(b, t, cfg.n_kv_heads, cfg.hd)
+    with jax.named_scope("attention"):
+        o, new_cache = _attend(cfg, ctx, q, k, v, cache, positions, phase,
+                               cache_len, chunk_mask, page_table)
+        o = o.transpose(0, 2, 1, 3).reshape(b, t, cfg.q_dim)
+    with jax.named_scope("o_proj"):
+        return layers.linear_apply(p["o"], o, ctx), new_cache
+
+
+def _attend(cfg: ModelConfig, ctx: Ctx, q, k, v, cache: Optional[dict],
+            positions: jax.Array, phase: str, cache_len, chunk_mask,
+            page_table) -> Tuple[jax.Array, Optional[dict]]:
+    """Rope, the KV write and the attention of one phase, for ``q``, ``k``
+    and ``v`` of shape (b, t, heads, hd).  Returns the attention output as
+    (b, heads, t, hd) and the updated cache (None without a cache)."""
+    t = q.shape[1]
     angles = layers.rope_angles(positions, cfg.hd, cfg.rope_theta)
     q = layers.apply_rope(q, angles, cfg.rope_style)
     k = layers.apply_rope(k, angles, cfg.rope_style)
@@ -354,8 +371,7 @@ def _attn_apply(cfg: ModelConfig, ctx: Ctx, p: dict, x: jax.Array,
                     q.transpose(0, 2, 1, 3), kc_r, vc_r, ks_r, vs_r,
                     page_table, offsets, k.transpose(0, 2, 1, 3),
                     v.transpose(0, 2, 1, 3), window=cfg.swa_window)
-                o = o.transpose(0, 2, 1, 3).reshape(b, t, cfg.q_dim)
-                return layers.linear_apply(p["o"], o, ctx), new_cache
+                return o, new_cache
             kc, vc = attention.paged_update_kv_cache(
                 cache["k"], cache["v"], k, v, page_table, offsets,
                 write_mask=admit)
@@ -366,8 +382,7 @@ def _attn_apply(cfg: ModelConfig, ctx: Ctx, p: dict, x: jax.Array,
                 k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
                 window=cfg.swa_window,
                 impl="pallas" if ctx.attn_impl == "pallas" else "xla")
-            o = o.transpose(0, 2, 1, 3).reshape(b, t, cfg.q_dim)
-            return layers.linear_apply(p["o"], o, ctx), new_cache
+            return o, new_cache
 
         def write_row(row_c, new, off, m):
             cur = jax.lax.dynamic_slice_in_dim(row_c, off, t, axis=0)
@@ -428,8 +443,7 @@ def _attn_apply(cfg: ModelConfig, ctx: Ctx, p: dict, x: jax.Array,
                     impl="pallas" if ctx.attn_impl == "pallas" else "xla",
                     kv_splits=ctx.kv_splits, kv_axis=ctx.kv_shard_axis,
                     kv_axis_size=ctx.kv_shard_size)
-                o = o.transpose(0, 2, 1, 3).reshape(b, t, cfg.q_dim)
-                return layers.linear_apply(p["o"], o, ctx), new_cache
+                return o, new_cache
             kc, vc = attention.paged_update_kv_cache(
                 cache["k"], cache["v"], k, v, page_table, cache_len)
             new_cache = {"k": kc, "v": vc}
@@ -440,8 +454,7 @@ def _attn_apply(cfg: ModelConfig, ctx: Ctx, p: dict, x: jax.Array,
                 impl="pallas" if ctx.attn_impl == "pallas" else "xla",
                 kv_splits=ctx.kv_splits, kv_axis=ctx.kv_shard_axis,
                 kv_axis_size=ctx.kv_shard_size)
-            o = o.transpose(0, 2, 1, 3).reshape(b, t, cfg.q_dim)
-            return layers.linear_apply(p["o"], o, ctx), new_cache
+            return o, new_cache
         if quantized:
             kq, ks = q_kv(k)
             vq, vs = q_kv(v)
@@ -475,8 +488,7 @@ def _attn_apply(cfg: ModelConfig, ctx: Ctx, p: dict, x: jax.Array,
             impl="pallas" if ctx.attn_impl == "pallas" else "xla",
             kv_splits=ctx.kv_splits, kv_axis=ctx.kv_shard_axis,
             kv_axis_size=ctx.kv_shard_size)
-    o = o.transpose(0, 2, 1, 3).reshape(b, t, cfg.q_dim)
-    return layers.linear_apply(p["o"], o, ctx), new_cache
+    return o, new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -568,23 +580,25 @@ def _block_apply(cfg: ModelConfig, ctx: Ctx, x: jax.Array, p: dict,
 
 def _embed_in(cfg: ModelConfig, params: dict, inputs: jax.Array,
               ctx: Ctx) -> jax.Array:
-    if cfg.frontend == "token":
-        x = layers.embed_apply(params["embed"], inputs)
-    else:  # audio/vlm stub: inputs are precomputed frame/patch embeddings
-        x = inputs
-    return x.astype(ctx.dtype)
+    with jax.named_scope("embed"):
+        if cfg.frontend == "token":
+            x = layers.embed_apply(params["embed"], inputs)
+        else:  # audio/vlm stub: inputs are precomputed frame/patch embeddings
+            x = inputs
+        return x.astype(ctx.dtype)
 
 
 def _lm_head(cfg: ModelConfig, params: dict, x: jax.Array,
              ctx: Ctx) -> jax.Array:
-    x = layers.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    if cfg.tie_embeddings and "embed" in params:
-        logits = jnp.einsum("btd,vd->btv", x,
-                            params["embed"]["tok"].astype(x.dtype))
-    else:
-        logits = layers.linear_apply(params["lm_head"], x, ctx,
-                                     ternary_w=cfg.ternary_head)
-    return ctx.c(logits, "logits")
+    with jax.named_scope("lm_head"):
+        x = layers.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        if cfg.tie_embeddings and "embed" in params:
+            logits = jnp.einsum("btd,vd->btv", x,
+                                params["embed"]["tok"].astype(x.dtype))
+        else:
+            logits = layers.linear_apply(params["lm_head"], x, ctx,
+                                         ternary_w=cfg.ternary_head)
+        return ctx.c(logits, "logits")
 
 
 def _run_layers(cfg: ModelConfig, ctx: Ctx, params: dict, x: jax.Array,

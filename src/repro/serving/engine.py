@@ -296,10 +296,12 @@ state integrates every token), so they fall back to PR 1's whole-prompt
 donor prefill + adopt — the fused decode block works for them unchanged.
 
 ``engine.stats`` is a per-WINDOW view (one ``run()``, or whatever span
-the caller delimits with ``reset_stats()``/``drain()``): aggregate *and*
-decode-only throughput (``decode_tokens / decode_wall_s``), TTFT p50/p95
-measured from each request's arrival, scheduler-beat and idle-sleep
-counts, and admission / interleave counters; paged mode adds KV pool
+the caller delimits with ``reset_stats()``/``drain()``): aggregate
+throughput, TTFT p50/p95 measured from each request's arrival,
+scheduler-beat and idle-sleep counts, the host seconds of each beat stage
+(``host_s``) with the window's longest beat (``beat_max_s``) and the stage
+that held most of it (``beat_max_stage``), and admission / interleave
+counters; paged mode adds KV pool
 gauges (page size, pool size, pages-in-use peak, pool utilization,
 live-token peak, reservation peak, page-starved admission deferrals).
 ``engine.lifetime`` accumulates across windows (arrivals, windows, status
@@ -343,6 +345,11 @@ from repro.runtime.fault import (CircuitBreaker, Watchdog, backoff_delay,
 from repro.serving.faultinject import FaultInjector, InjectedFault
 
 _SEED_MOD = 2 ** 31 - 1
+
+# The host stages of a scheduler beat, in beat order.  Each is a host span
+# ``engine.<stage>`` in the profiler's trace and a sum in
+# ``stats["host_s"]`` (see ``ServingEngine._stage``).
+STAGES = ("police", "admit", "wave", "first_sync", "block", "readback")
 
 
 class RequestStatus(enum.Enum):
@@ -850,19 +857,22 @@ class ServingEngine:
             depends only on the request, never on slot or tick order.  The
             PRNG work is skipped entirely (lax.cond) when the whole batch is
             greedy."""
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-            def with_temperature(_):
-                def one(seed, idx, row, t):
-                    key = jax.random.fold_in(jax.random.PRNGKey(seed), idx)
-                    return jax.random.categorical(
-                        key, row.astype(jnp.float32) / jnp.maximum(t, 1e-6))
-                sampled = jax.vmap(one)(seeds, emitted, logits,
-                                        temps).astype(jnp.int32)
-                return jnp.where(temps > 0.0, sampled, greedy)
+                def with_temperature(_):
+                    def one(seed, idx, row, t):
+                        key = jax.random.fold_in(jax.random.PRNGKey(seed),
+                                                 idx)
+                        return jax.random.categorical(
+                            key,
+                            row.astype(jnp.float32) / jnp.maximum(t, 1e-6))
+                    sampled = jax.vmap(one)(seeds, emitted, logits,
+                                            temps).astype(jnp.int32)
+                    return jnp.where(temps > 0.0, sampled, greedy)
 
-            return jax.lax.cond(jnp.any(temps > 0.0), with_temperature,
-                                lambda _: greedy, None)
+                return jax.lax.cond(jnp.any(temps > 0.0), with_temperature,
+                                    lambda _: greedy, None)
 
         def _prefill_chunks(params, tokens, cache, bt, offsets, admit_mask,
                             last_idx, seeds, temps, emit_idx):
@@ -1120,6 +1130,8 @@ class ServingEngine:
         }
         self.lifetime.update({k: 0 for k in _STATUS_COUNTERS.values()})
         self._closed = False
+        self._span = None  # the beat stage's open host span
+        self._span_stage, self._span_t0 = None, 0.0
         self._reset_engine_state()
         self.reset_stats()
 
@@ -1809,6 +1821,7 @@ class ServingEngine:
         prompts are being admitted or how long they are."""
         self.stats["prefill_chunks"] += 1
         self._sched_epoch += 1  # a wave mutates device-visible inputs
+        self._stage("wave")
         if not self._chunked:  # recurrent: whole prompt, donor + adopt,
             i = next(iter(pending))  # one admission per wave
             admit = pending.pop(i)
@@ -1816,9 +1829,12 @@ class ServingEngine:
             toks = np.asarray(admit["prompt"], np.int32)[None]
             one_cache = transformer.init_cache(self.cfg, 1, plen,
                                                self.cache_dtype)
+            self._tag("requests", (req,))
             logits, one_cache = self._prefill_full(
                 self.params, jnp.asarray(toks), one_cache,
                 jnp.asarray([plen], jnp.int32))
+            self._stage("first_sync")
+            self._tag("requests", (req,))
             tok = self._first_token(logits, req, len(admit["carried"]))
             cache = self._adopt(cache, one_cache, jnp.asarray(i, jnp.int32))
             if self._dev_active:
@@ -1830,6 +1846,7 @@ class ServingEngine:
                     np.asarray([req.temperature if i == j else 0.0
                                 for j in range(self.slots)], np.float32))
             self._finish_admission(slots, admit, tok)
+            self._stage()
             return cache
         n, c = self.slots, self.prefill_chunk
         toks = np.zeros((n, c), np.int32)
@@ -1875,12 +1892,18 @@ class ServingEngine:
             if admit["next"] >= admit["n_chunks"]:
                 completing.append(i)
         if not mask.any():
+            self._stage()
             return cache  # every admission aborted this wave
+        # the rows still pending are the ones this wave dispatches
+        self._tag("requests", (a["req"] for a in pending.values()))
         first, cache = self._prefill_chunks(
             self.params, jnp.asarray(toks), cache, self._bt_device(),
             jnp.asarray(offs), jnp.asarray(mask), jnp.asarray(last),
             jnp.asarray(seeds), jnp.asarray(temps), jnp.asarray(emit0))
+        self._stage()  # the wave ends when its dispatch returns
         if completing:
+            self._stage("first_sync")
+            self._tag("requests", (pending[i]["req"] for i in completing))
             if self._dev_active:
                 # activate the lanes on device BEFORE the host sync: the
                 # wave's on-device first tokens flow straight into the
@@ -1892,6 +1915,7 @@ class ServingEngine:
             ft = np.asarray(first)  # sync only when an admission completes
             for i in completing:
                 self._finish_admission(slots, pending.pop(i), int(ft[i]))
+            self._stage()
         return cache
 
     def _merge_admissions(self, admits, first, seeds, temps) -> None:
@@ -1954,6 +1978,7 @@ class ServingEngine:
 
     def _run_decode_block(self, cache, slots):
         st = self.stats
+        self._stage("block")  # ends when the block's dispatch returns
         if self.paged:
             if not self._dev_active:
                 # host-driven: grow each live lane's page list to cover
@@ -1977,6 +2002,7 @@ class ServingEngine:
             live = sum(s.cache_len for s in slots if s.active)
             st["kv_live_tokens_peak"] = max(st["kv_live_tokens_peak"], live)
         if not any(s.active for s in slots):
+            self._stage()
             return cache  # growth faults may have emptied the batch
         self._note_dispatch()
         st["decode_blocks"] += 1
@@ -2024,8 +2050,6 @@ class ServingEngine:
         the host-side call.  Retries are legal because the seam fires
         BEFORE the jit call — no donated buffer has been consumed when a
         retryable fault raises."""
-        t_blk = time.perf_counter()
-        st = self.stats
         fi = self.fault_injector
         if self._dev_active:
             def dispatch():
@@ -2042,8 +2066,8 @@ class ServingEngine:
                 dispatch, max_retries=self.dispatch_retries,
                 retry_on=(InjectedFault,),
                 backoff_s=self.dispatch_backoff_s, seed=self.seed)()
+            self._stage()
             self._inflight.append((blk, mask, bad))
-            st["decode_wall_s"] += time.perf_counter() - t_blk
             # fetch one block behind: drain block N while block N+1 runs
             self._drain_blocks(slots, depth=1)
             return cache
@@ -2070,8 +2094,10 @@ class ServingEngine:
             dispatch, max_retries=self.dispatch_retries,
             retry_on=(InjectedFault,),
             backoff_s=self.dispatch_backoff_s, seed=self.seed)()
-        self._process_block(slots, blk, mask, bad, gating=True)
-        st["decode_wall_s"] += time.perf_counter() - t_blk
+        self._stage("readback")
+        self._tag("retired",
+                  self._process_block(slots, blk, mask, bad, gating=True))
+        self._stage()
         return cache
 
     def _degrade(self, slots, reason: str) -> None:
@@ -2202,15 +2228,19 @@ class ServingEngine:
         """Read back queued decode blocks down to ``depth`` still in
         flight (depth=1 is the steady-state one-block-behind pipeline;
         depth=0 the final drain)."""
-        if not self._inflight:
+        if len(self._inflight) <= depth:
             return
-        t_d = time.perf_counter()
+        self._stage("readback")
+        retired = []
         while len(self._inflight) > depth:
             blk, mask, bad = self._inflight.popleft()
-            self._process_block(slots, blk, mask, bad, gating=False)
-        self.stats["decode_wall_s"] += time.perf_counter() - t_d
+            retired += self._process_block(slots, blk, mask, bad,
+                                           gating=False)
+        self._tag("retired", retired)
+        self._stage()
 
-    def _process_block(self, slots, blk, mask, bad, *, gating: bool) -> None:
+    def _process_block(self, slots, blk, mask, bad, *,
+                       gating: bool) -> List[Request]:
         """Fold one decode block's readback into the host mirror: run the
         output-integrity guards, extend outputs, advance lengths, retire
         finished lanes.  ``gating`` marks a readback the next dispatch
@@ -2224,7 +2254,8 @@ class ServingEngine:
         host-side token-range check (catches readback/interconnect
         corruption the device could not see).  A flagged lane retires
         FAILED with the tokens it had before this block; its prefix
-        registrations are withdrawn; every other lane is untouched."""
+        registrations are withdrawn; every other lane is untouched.
+        Returns the requests the fold retired."""
         blk = np.asarray(blk)
         mask = np.asarray(mask)
         bad = np.asarray(bad)
@@ -2234,29 +2265,29 @@ class ServingEngine:
                                  bad_token=self.cfg.vocab_size + 7)
         st = self.stats
         st["decode_tokens"] += int(mask.sum())
-        retired = False
+        retired = []
         live_after = 0  # post-append live tokens, counted before any free
         for i, s in enumerate(slots):
             if not s.active:
                 continue
             if bad[i]:
                 st["integrity_faults"] += 1
+                retired.append(s.request)
                 self._fault_retire(
                     slots, i, RequestStatus.FAILED,
                     "non-finite logits in fused block (lane isolated; "
                     "the block's tokens for this lane are discarded)",
                     rollback_prefix=True)
-                retired = True
                 continue
             new_arr = blk[i][mask[i]]
             if new_arr.size and (int(new_arr.min()) < 0 or
                                  int(new_arr.max()) >= self.cfg.vocab_size):
                 st["integrity_faults"] += 1
+                retired.append(s.request)
                 self._fault_retire(
                     slots, i, RequestStatus.FAILED,
                     "emitted token id out of range (corrupt readback; "
                     "lane isolated)", rollback_prefix=True)
-                retired = True
                 continue
             new = new_arr.tolist()
             s.tokens.extend(int(t) for t in new)
@@ -2272,8 +2303,8 @@ class ServingEngine:
                         self.on_token(s.request, int(t))
             if (len(s.tokens) >= s.request.max_new_tokens
                     or s.cache_len >= self.max_seq):
+                retired.append(s.request)
                 self._free_slot(slots, i)
-                retired = True
         if self.paged:
             # the gauge at block entry misses the block's own appends; this
             # post-append sample makes the live-token peak exact
@@ -2293,6 +2324,7 @@ class ServingEngine:
             raise RuntimeError(
                 "active lane at cache_len >= max_seq: parked decode writes "
                 "could clobber a live token")
+        return retired
 
     # -- main loop ---------------------------------------------------------
 
@@ -2462,7 +2494,6 @@ class ServingEngine:
         self.stats = {"admissions": 0, "mid_flight_admissions": 0,
                       "prefill_chunks": 0, "decode_steps": 0,
                       "decode_blocks": 0, "decode_tokens": 0,
-                      "decode_wall_s": 0.0,
                       "max_chunks_between_decode_blocks": 0,
                       "host_block_syncs": 0, "steady_state_blocks": 0,
                       # beat accounting (the busy-spin regression guard: a
@@ -2470,6 +2501,11 @@ class ServingEngine:
                       # capped-sleep poll loop)
                       "scheduler_beats": 0, "idle_sleeps": 0,
                       "idle_wait_s": 0.0,
+                      # host seconds per beat stage, and the longest beat
+                      # with the stage that held most of it: they name a
+                      # host stall in a run that takes no trace
+                      "host_s": dict.fromkeys(STAGES, 0.0),
+                      "beat_max_s": 0.0, "beat_max_stage": None,
                       # robustness gauges — always present, every mode
                       "requests_completed": 0, "requests_rejected": 0,
                       "requests_failed": 0, "requests_timed_out": 0,
@@ -2567,6 +2603,7 @@ class ServingEngine:
         # deterministic per-request default; normalize to int32 range
         req.seed = ((self.seed * 1000003 + self._arrivals)
                     if req.seed is None else int(req.seed)) % _SEED_MOD
+        req._ordinal = self._arrivals  # names the request's host spans
         self._arrivals += 1
         self.lifetime["arrivals"] += 1
         req._arrival_t = now
@@ -2600,12 +2637,55 @@ class ServingEngine:
         one block no matter the arrival pattern.  Drive the engine by
         looping ``step()`` — honoring ``StepOutcome.idle_until`` by
         sleeping instead of re-calling immediately — or use
-        ``drain()``/``run()``, which do exactly that."""
-        slots, pending, queue = self._lanes, self._pending, self._queue
+        ``drain()``/``run()``, which do exactly that.
+
+        Each stage runs as a host span ``engine.<stage>`` (``STAGES``);
+        the beat's seconds go to ``stats["beat_max_s"]`` when it is the
+        window's longest."""
         if not self.has_work:
             return StepOutcome(worked=False, remaining=0)
+        host = self.stats["host_s"]
+        before, t0 = dict(host), time.perf_counter()
+        try:
+            return self._run_beat()
+        finally:
+            self._stage()
+            beat = time.perf_counter() - t0
+            if beat > self.stats["beat_max_s"]:
+                self.stats["beat_max_s"] = beat
+                self.stats["beat_max_stage"] = max(
+                    STAGES, key=lambda k: host[k] - before[k])
+
+    def _stage(self, name: Optional[str] = None) -> None:
+        """End the beat's open stage and, given ``name``, open the next:
+        a host span ``engine.<name>`` in the profiler's trace, whose
+        ``perf_counter`` seconds are added to ``stats["host_s"][name]``
+        when it ends.  The stages of one beat follow each other and never
+        nest."""
+        now = time.perf_counter()
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+            self.stats["host_s"][self._span_stage] += now - self._span_t0
+        if name is not None:
+            self._span = jax.profiler.TraceAnnotation("engine." + name)
+            self._span.__enter__()
+            self._span_stage, self._span_t0 = name, now
+
+    def _tag(self, key: str, requests) -> None:
+        """Put the arrival ordinals of ``requests`` on the open stage's
+        span as metadata ``key`` (space-separated), so the spans of one
+        request share its ordinal.  Only while a trace is being taken."""
+        if self._span is not None and self._span.is_enabled():
+            ords = " ".join(str(r._ordinal) for r in requests)
+            if ords:
+                self._span.set_metadata(**{key: ords})
+
+    def _run_beat(self) -> StepOutcome:
+        slots, pending, queue = self._lanes, self._pending, self._queue
         self._ensure_cache()
         self.stats["scheduler_beats"] += 1
+        self._stage("police")
         # cancellation + deadline sweep over every request pool, once
         # per block boundary (host-side only, no device sync)
         self._police(slots, pending, queue)
@@ -2630,6 +2710,7 @@ class ServingEngine:
         # pool, so lazy page growth can't fail mid-flight.
         # padded lanes (slot-axis rounding under data sharding) sit past
         # _usable_slots and are never assigned — they tick fully masked
+        self._stage("admit" if queue else None)
         for i, s in enumerate(slots[:self._usable_slots]):
             if not queue:
                 break
@@ -2726,6 +2807,7 @@ class ServingEngine:
                         continue
                 if any(o.active for o in slots):
                     self.stats["mid_flight_admissions"] += 1
+        self._stage()
         # one batched prefill wave — in-flight lanes stall for at most
         # this one dispatch before the next decode block runs
         if pending:
@@ -2827,8 +2909,6 @@ class ServingEngine:
             "wall_s": wall,
             "total_new_tokens": total,
             "tokens_per_s": total / wall if wall > 0 else float("inf"),
-            "decode_tok_s": (st["decode_tokens"] / st["decode_wall_s"]
-                             if st["decode_wall_s"] > 0 else float("inf")),
             # per-request TTFT, measured from each request's ARRIVAL
             # (submit time) — under batch run() arrival coincides with the
             # window start, so the batch semantics are unchanged

@@ -12,6 +12,7 @@ width.  Subprocesses here stay on the CPU.
 from __future__ import annotations
 
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -126,3 +127,24 @@ def test_compile_cache_dir_defaults_to_checkout(monkeypatch):
         assert jax.config.jax_compilation_cache_dir == path
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_keys_on_program_metadata(monkeypatch, tmp_path):
+    """An entry compiled before a ``jax.named_scope`` changed must not be
+    found again (its profile would show the old scopes), and the key must
+    not depend on where the checkout lies."""
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    flags = ("jax_compilation_cache_include_metadata_in_key",
+             "jax_hlo_source_file_canonicalization_regex")
+    before = [getattr(jax.config, f) for f in flags]
+    try:
+        jax.config.update(flags[0], False)
+        compile_cache.enable_compile_cache()
+        assert getattr(jax.config, flags[0]) is True
+        strip = getattr(jax.config, flags[1])
+        here = os.path.join(ROOT, "src", "repro", "models", "transformer.py")
+        assert re.sub(strip, "", here) == os.path.join(
+            "src", "repro", "models", "transformer.py")
+    finally:
+        for f, v in zip(flags, before):
+            jax.config.update(f, v)
