@@ -81,22 +81,6 @@ def ternarize_ste(w: jax.Array, eps: float = 1e-5) -> jax.Array:
 # INT8 activation quantization (per-token ABSMAX, the paper's RMS-MAX output)
 # ---------------------------------------------------------------------------
 
-def absmax_quant_values(x: jax.Array, axis: int = -1, eps: float = 1e-5
-                        ) -> Tuple[jax.Array, jax.Array]:
-    """absmax_quant with the quantized values kept in f32.
-
-    Exactly the int8 values (round/clip already applied), just not cast —
-    the GEMM-friendly form used by the pre-decoded serving hot path, where
-    integer-valued f32 operands keep the contraction exact.  Single source
-    of truth for the quantization recipe; absmax_quant delegates here.
-    """
-    xf = x.astype(jnp.float32)
-    amax = jnp.maximum(jnp.max(jnp.abs(xf), axis=axis, keepdims=True), eps)
-    scale = amax / 127.0
-    q = jnp.clip(jnp.round(xf / scale), -127, 127)
-    return q, scale
-
-
 def absmax_quant(x: jax.Array, axis: int = -1, eps: float = 1e-5
                  ) -> Tuple[jax.Array, jax.Array]:
     """Per-token absmax int8 quantization.
@@ -104,7 +88,10 @@ def absmax_quant(x: jax.Array, axis: int = -1, eps: float = 1e-5
     Returns (int8 values, f32 scale with the quantized axis kept at size 1)
     such that x ~= values * scale.
     """
-    q, scale = absmax_quant_values(x, axis, eps)
+    xf = x.astype(jnp.float32)
+    amax = jnp.maximum(jnp.max(jnp.abs(xf), axis=axis, keepdims=True), eps)
+    scale = amax / 127.0
+    q = jnp.clip(jnp.round(xf / scale), -127, 127)
     return q.astype(jnp.int8), scale
 
 

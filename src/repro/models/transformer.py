@@ -136,11 +136,9 @@ def predecode_packed(cfg: ModelConfig, params: dict) -> dict:
     """Decode every packed linear's base-3 codes into dense int8 ternary
     weights (vmapped over the stacked layer axis).
 
-    The serving engine calls this at the top of its fused decode block, so
-    the weight unpack runs once per block and is amortized across the
-    block's ticks — the software analogue of the paper's decode bandwidth
-    argument (batch tokens against one pass over the weight stream).
-    Outputs are bit-identical to running on the packed params (see
+    The serving engine calls this once, when it is built, and serves every
+    admission wave and decode block from the resident result.  Outputs are
+    bit-identical to running on the packed params (see
     ``bitlinear.predecode``).  MoE expert banks keep their own packed
     format and are left untouched.
     """

@@ -13,14 +13,14 @@ keeps the whole serving tick on device:
     writes, ``cache_len``/``emitted`` bookkeeping and done-masking all stay
     on device; the host gets back a ``(slots, decode_block)`` token block
     plus emit masks once per block instead of once per token.  The packed
-    ternary weights are pre-decoded once per block, outside the scan
-    (``transformer.predecode_packed``), amortizing the base-3 unpack over
-    the block's ticks — the software analogue of the paper's decode
-    bandwidth argument (batch tokens against one pass over the weight
-    stream).  Lanes that finish mid-block emit pad tokens (0) under the
-    mask so the scan shape stays static; their cache writes are parked at
-    the row tail (position ``max_seq - 1``), which is either masked by the
-    live length or overwritten before it is ever attended.
+    ternary weights are expanded once, when the engine is built
+    (``transformer.predecode_packed``), and stay resident as int8: every
+    block and wave contracts int8 activations against them (int8 x int8 ->
+    int32) and re-derives nothing.  Lanes that finish mid-block emit pad
+    tokens (0) under the mask so the scan shape stays static; their cache
+    writes are parked at the row tail (position ``max_seq - 1``), which is
+    either masked by the live length or overwritten before it is ever
+    attended.
   * **chunked in-place prefill, batched across slots** — admission runs
     ``prefill_chunk``-sized *wave* dispatches (``transformer.prefill_chunk``)
     in which EVERY pending admission writes its chunk's KV straight into its
@@ -882,11 +882,9 @@ class ServingEngine:
             at per-row emitted index ``emit_idx`` — 0 for a fresh admission,
             the carried-token count for a retry replay (so temperature
             sampling folds in the same per-token key an uninterrupted run
-            would have used).  Weights are pre-decoded once per wave (exact
-            f32-GEMM path), like the decode block.  In paged mode ``bt`` is
-            the (slots, pages_per_slot) block table and the chunk KV is
-            scattered into the page pool."""
-            params = transformer.predecode_packed(cfg_, params)
+            would have used).  In paged mode ``bt`` is the (slots,
+            pages_per_slot) block table and the chunk KV is scattered into
+            the page pool."""
             logits, cache = transformer.prefill_chunk(
                 cfg_, params, tokens, ctx_, cache, offsets=offsets,
                 admit_mask=admit_mask, last_index=last_idx,
@@ -946,12 +944,6 @@ class ServingEngine:
                           max_new, active, temps, seeds, nan_mask):
             """Fused multi-tick decode: scan `decode_block` ticks on device.
 
-            The packed ternary weights are pre-decoded ONCE here, outside
-            the scan, so the base-3 unpack is amortized over the block's
-            ticks (the paper's decode-bandwidth argument in software: batch
-            tokens against one pass over the weight stream) — bit-identical
-            outputs to the packed path.
-
             Finished lanes keep ticking under a mask (static scan shape):
             they emit pad token 0 and their bookkeeping freezes.  Their KV
             write is parked at flat address ``max_seq``: contiguous mode
@@ -963,7 +955,6 @@ class ServingEngine:
             the final page's slack row when page_size does not divide
             max_seq.
             """
-            params = transformer.predecode_packed(cfg_, params)
             tick = _make_tick(params, bt, max_new, temps, seeds, nan_mask)
             carry = (tokens, cache, cache_len, emitted, active,
                      jnp.zeros_like(active))
@@ -978,7 +969,6 @@ class ServingEngine:
             the donated ``state`` pytree and is threaded block-to-block on
             device — dispatching block N+1 needs no host value from block
             N, so the host never sits between blocks in steady state."""
-            params = transformer.predecode_packed(cfg_, params)
             tick = _make_tick(params, bt, state["max_new"], state["temps"],
                               state["seeds"], nan_mask)
             carry = (state["last_token"], cache, state["cache_len"],
@@ -1101,6 +1091,18 @@ class ServingEngine:
             # they would be re-broadcast to every shard by each dispatch
             self.params = jax.device_put(packed_params,
                                          NamedSharding(mesh, rep))
+        # expand the ternary codes once, in one program, and keep the int8
+        # matrices resident: nothing about them changes between dispatches.
+        # Only the stacked layers are expanded; the embedding and norms stay
+        # the caller's arrays rather than copies.
+        def expand(layers):
+            return transformer.predecode_packed(
+                cfg_, {"layers": layers})["layers"]
+
+        placed = ({} if mesh is None
+                  else {"out_shardings": NamedSharding(mesh, P())})
+        self.params = dict(self.params, layers=jax.jit(expand, **placed)(
+            self.params["layers"]))
         self._admit_lanes = _admit_lanes
         self._set_bt_row = _set_bt_row
         self._kill_lane = _kill_lane

@@ -407,3 +407,46 @@ def test_update_kv_cache_per_slot_positions():
     assert (vc2[0, 2] == 2).all() and (vc2[1, 5] == 2).all()
     kc2[0, 2] = kc2[1, 5] = vc2[0, 2] = vc2[1, 5] = 0
     assert (kc2 == 0).all() and (vc2 == 0).all()
+
+
+def test_weights_expanded_once_and_contracted_as_int8(served_model,
+                                                      monkeypatch):
+    """The ternary codes are expanded once, when the engine is built, and
+    not by any dispatch: the admission wave and the decode block carry no
+    ``predecode`` scope and contract every linear on int8 operands."""
+    cfg, packed, ctx = served_model
+    calls = []
+    expand = transformer.predecode_packed
+    monkeypatch.setattr(transformer, "predecode_packed",
+                        lambda *a, **k: calls.append(1) or expand(*a, **k))
+    eng = ServingEngine(cfg, packed, max_seq=32, batch_slots=2,
+                        decode_block=4, prefill_chunk=4, ctx=ctx)
+    for i in range(3):
+        eng.submit(Request(prompt=np.arange(3 + 2 * i) % cfg.vocab_size,
+                           max_new_tokens=7))
+    steps = 0
+    while eng.step().remaining:
+        steps += 1
+    assert steps > 3
+    assert eng.stats["prefill_chunks"] > 1 and eng.stats["decode_blocks"] > 1
+    assert len(calls) == 1
+
+    n = eng.slots
+    i32 = jax.ShapeDtypeStruct((n,), jnp.int32)
+    wave = eng._prefill_chunks.lower(
+        eng.params, jax.ShapeDtypeStruct((n, eng.prefill_chunk), jnp.int32),
+        eng._cache, eng._bt_device(), i32,
+        jax.ShapeDtypeStruct((n,), jnp.bool_), i32, i32,
+        jax.ShapeDtypeStruct((n,), jnp.float32), i32)
+    block = eng._decode_block_dev.lower(eng.params, eng._state, eng._cache,
+                                        eng._bt_device(), eng._no_nan)
+    weights = [w.shape[1:] for path, w in
+               jax.tree_util.tree_flatten_with_path(eng.params["layers"])[0]
+               if path[-1].key == "wt"]
+    assert len(weights) == 4  # qkv, o, gate|up, down
+    for lowered in (wave, block):
+        text = lowered.as_text(debug_info=True)
+        assert "/predecode/" not in text
+        for rows, cols in weights:
+            assert f"tensor<{rows}x{cols}xi8>) -> " in text
+            assert f"tensor<{rows}x{cols}xf32>" not in text

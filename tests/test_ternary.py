@@ -74,6 +74,41 @@ def test_packed_xla_matmul_matches_oracle():
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
+@pytest.mark.parametrize("n_in,n_outs,row_multiple,ones", [
+    (40, (24,), 1, False),
+    (37, (24,), 64, False),
+    (96, (64, 16, 16), 64, False),
+    (96, (48, 48), 64, False),
+    # |acc| = 127 * n_in > 2**24: past where an f32 accumulator stays exact
+    ((1 << 24) // 127 + 7_001, (8,), 64, True),
+], ids=["single", "padded_n_in", "fused_qkv", "fused_gate_up", "wide_n_in"])
+def test_predecoded_int8_contraction_is_exact(n_in, n_outs, row_multiple,
+                                              ones):
+    """The resident int8 weights (predecode / predecode_fused) contract to
+    exactly what the packed XLA path and the dense int32 oracle give."""
+    g = 5
+    rng = np.random.default_rng(n_in + len(n_outs))
+    x = (np.ones((3, n_in), np.float32) if ones
+         else rng.normal(size=(3, n_in)).astype(np.float32) * 3.0)
+    parts = []
+    for k in n_outs:
+        wt = (np.ones((n_in, k), np.int8) if ones
+              else rng.integers(-1, 2, size=(n_in, k)).astype(np.int8))
+        parts.append({"codes": ternary.pack_ternary(jnp.asarray(wt), g,
+                                                    row_multiple),
+                      "gamma": jnp.float32(rng.uniform(0.5, 1.5))})
+    dec = (bitlinear.predecode(parts[0], g=g) if len(parts) == 1
+           else bitlinear.predecode_fused(parts, g=g))
+    assert dec["wt"].dtype == jnp.int8
+    got = np.asarray(bitlinear.apply_predecoded(dec, jnp.asarray(x),
+                                                out_dtype=jnp.float32))
+    for impl in (bitlinear.IMPL_XLA, bitlinear.IMPL_REF):
+        want = np.concatenate([np.asarray(bitlinear.apply_packed(
+            p, jnp.asarray(x), g=g, impl=impl, out_dtype=jnp.float32))
+            for p in parts], axis=-1)
+        np.testing.assert_array_equal(got, want)
+
+
 def test_absmax_quant_bounds_and_recon():
     x = jax.random.normal(jax.random.PRNGKey(1), (5, 33)) * 4.0
     q, s = ternary.absmax_quant(x)
