@@ -45,7 +45,7 @@ sys.path.insert(0, ROOT)  # importing bench puts the program's src/ on it
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from bench import check, serve, trace_reduce, weights  # noqa: E402
+from bench import archs, check, serve, trace_scopes, weights  # noqa: E402
 from bench.clock import CompileClock  # noqa: E402
 from bench.peaks import peaks  # noqa: E402
 from bench.traffic import Traffic  # noqa: E402
@@ -127,13 +127,16 @@ class Tracer:
         self.t1 = self.t1 or time.perf_counter()
         self.engine = None  # the engine is freed before the reference runs
 
-    def reduce(self) -> dict:
+    def reduce(self, scopes: tuple) -> dict:
+        """The trace reduced by ``trace_scopes``, ops attributed to the
+        innermost of ``scopes``; the trace's files are removed."""
         try:
             paths = glob.glob(os.path.join(self.dir, "plugins", "profile",
                                            "*", "*.xplane.pb"))
             if not paths:
                 raise RuntimeError("the profiler wrote no trace")
-            return trace_reduce.reduce(trace_reduce.load(paths[0]))
+            return trace_scopes.reduce(trace_scopes.load(paths[0], scopes),
+                                       scopes=scopes)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
 
@@ -154,6 +157,24 @@ def traced_run(tracer, summary, win, cfg, engine_cfg, peaks) -> dict:
                       for k in tracer.stats0},
             "decode_contexts": contexts, "prompt_lengths": prompts,
             "peaks": peaks}
+
+
+def read_per_layer(per_layer: list, run: dict) -> dict:
+    """Each per-layer metric its reader finds something to read in."""
+    metrics = {}
+    for m in per_layer:
+        mod = importlib.import_module(f"bench.metrics.{m['name']}")
+        value = mod.read(run)
+        if value is not None:
+            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    return metrics
+
+
+def trace_breakdown(summary: dict) -> dict:
+    """The ops, idle gaps and name scopes that took the traced window."""
+    return {"device_ops": [list(x) for x in summary["device_ops"]],
+            "idle_gaps": summary["idle_gaps"],
+            "device_scopes": trace_scopes.top_scopes(summary["scopes"])}
 
 
 def warm_up(engine, cell: dict, vocab: int) -> None:
@@ -206,19 +227,14 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
               "count": jax.device_count(), "memory_peak_bytes": mem}
     metrics, breakdown = {}, None
     if tracer:
-        summary = tracer.reduce()
+        summary = tracer.reduce(archs.scopes(cfg))
         device["busy_s"] = summary["busy_s"]
         device["window_s"] = summary["window_s"]
         run = traced_run(tracer, summary, win, cfg, cell["engine"],
                          peaks(dev.device_kind) if dev.platform == "tpu"
                          else spec.get("peaks"))
-        for m in spec["per_layer"]:
-            mod = importlib.import_module(f"bench.metrics.{m['name']}")
-            value = mod.read(run)
-            if value is not None:
-                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-        breakdown = {"device_ops": [list(x) for x in summary["device_ops"]],
-                     "idle_gaps": summary["idle_gaps"]}
+        metrics = read_per_layer(spec["per_layer"], run)
+        breakdown = trace_breakdown(summary)
     else:
         for m in spec["end_to_end"]:
             value = setup_s if m["name"] == "setup_s" else e2e[m["name"]]
@@ -247,7 +263,8 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
                  "lateness_max_ms": float(lateness.max()) * 1e3,
                  "engine": {k: stats[k] for k in (
                      "admissions", "prefill_chunks", "decode_blocks",
-                     "decode_tokens", "sched_fallbacks", "degraded_blocks")}},
+                     "decode_tokens", "sched_fallbacks", "degraded_blocks",
+                     "host_s", "beat_max_s", "beat_max_stage")}},
         "checks": checks,
     }
 

@@ -51,7 +51,8 @@ def test_traced_run_reports_per_layer_metrics():
     # the CPU has no device plane: nothing device-timed is reported
     assert "device_idle_share" not in r["metrics"]
     assert r["device"]["window_s"] > 0
-    assert set(r["breakdown"]) == {"device_ops", "idle_gaps"}
+    assert set(r["breakdown"]) == {"device_ops", "idle_gaps",
+                                   "device_scopes"}
 
 
 def test_delivery_faults_count_short_and_refused_requests():

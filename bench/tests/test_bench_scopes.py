@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import trace_reduce, trace_scopes, weights
+from bench import archs, trace_reduce, trace_scopes, weights
 from bench.tests import helpers
 from repro.serving import Request, ServingEngine
 from repro.serving.engine import STAGES
@@ -137,6 +137,21 @@ def test_scope_of():
         "attention"
     assert trace_scopes.scope_of("jit(f)/lm_head/down_proj") == "lm_head"
     assert trace_scopes.scope_of("") == trace_scopes.UNSCOPED
+    # a scope the default tuple lacks counts only where it is passed
+    assert trace_scopes.scope_of("jit(f)/ssm/x") == trace_scopes.UNSCOPED
+    assert trace_scopes.scope_of("jit(f)/down/ssm/x",
+                                 trace_scopes.SCOPES + ("ssm",)) == "ssm"
+
+
+def test_scopes_passed_to_reduce_take_their_ops():
+    t = synthetic()
+    t["op_paths"]["jit__decode_block_dev(9)"]["copy.2"] = \
+        "jit(_decode_block_dev)/ssm/scan/copy"
+    r = trace_scopes.reduce(t, scopes=trace_scopes.SCOPES + ("ssm",))
+    assert r["scopes"]["_decode_block_dev"] == pytest.approx(
+        {"qkv": 0.002, "down": 0.003, "ssm": 0.001,
+         "unscoped": 0.003 + 0.002})
+    assert "ssm" not in trace_scopes.reduce(t)["scopes"]["_decode_block_dev"]
 
 
 # -- the six accepted metrics read the same on the accepted fixture ----------
@@ -250,6 +265,32 @@ def test_paths_come_from_the_stored_programs(tmp_path):
                if trace_scopes.scope_of(p) != trace_scopes.UNSCOPED)
 
 
+def test_a_scope_of_an_architecture_s_own_comes_from_the_programs(tmp_path):
+    """A scope passed beside the default ones gets its ops' paths; left
+    out, its fusion takes the path of the default scope it holds."""
+    known = trace_scopes.SCOPES + ("ssm",)
+
+    @jax.jit
+    def f(x):
+        with jax.named_scope("qkv"):
+            y = jnp.tanh(x @ x) * 2
+        with jax.named_scope("ssm"):
+            y = jnp.cumsum(y @ x, axis=0)
+        return y + 1
+
+    x = jnp.ones((16, 16))
+    f(x).block_until_ready()
+    with open(record(tmp_path, lambda: f(x).block_until_ready()),
+              "rb") as fh:
+        xspace = fh.read()
+    paths = trace_scopes.hlo_op_paths(xspace, known)
+    (prog,) = [k for k in paths if trace_reduce.module_name(k) == "f"]
+    assert {"qkv", "ssm"} <= {trace_scopes.scope_of(p, known)
+                              for p in paths[prog].values()}
+    default = trace_scopes.hlo_op_paths(xspace)[prog]
+    assert "ssm" not in {trace_scopes.scope_of(p) for p in default.values()}
+
+
 # -- a trace recorded on a TPU v5e with the scopes and spans -----------------
 
 @pytest.fixture(scope="module")
@@ -308,3 +349,75 @@ def test_chip_trace_breakdown_names_what_can_be_fixed(scoped):
     assert not any(n.startswith("while") for n, _ in r["device_ops"][:5])
     assert all(n.startswith("engine.") for n, sec in r["idle_gaps"]
                if sec > 1e-3)
+
+
+# -- run.py's reduction of a traced run, on the chip fixture -----------------
+
+LISTED = ("device_idle_share", "lane_occupancy", "prefill_wave_ms",
+          "decode_tick_ms", "decode_block_roofline", "step_mfu")
+SCOPED_METRICS = ("decode_linear_ms", "decode_attention_ms", "lm_head_ms",
+                  "wave_queue_ms")
+
+
+def traced_metrics(summary):
+    """The per-layer metrics of BENCHMARK.json as run.py reads them from a
+    reduced trace of the bitnet-0.73b.edge cell, with a window that
+    decoded 14 tokens at contexts 101-114 after a 100-token prompt."""
+    from types import SimpleNamespace
+
+    from bench import run
+    from bench.peaks import peaks
+
+    spec = run.load_cell("bitnet-0.73b.edge")
+    tracer = SimpleNamespace(
+        t0=0.0, t1=1.0,
+        stats0={"decode_tokens": 0, "decode_blocks": 0,
+                "prefill_chunks": 0, "admissions": 0},
+        stats1={"decode_tokens": 14, "decode_blocks": 2,
+                "prefill_chunks": 1, "admissions": 1})
+    served = SimpleNamespace(prompt=np.zeros(100, np.int32), first_t=0.5,
+                             token_t=[0.5 + 0.01 * j for j in range(15)])
+    r = run.traced_run(tracer, summary, SimpleNamespace(served=[served]),
+                       spec["cfg"], spec["cell"]["engine"],
+                       peaks("TPU v5 lite"))
+    return run.read_per_layer(spec["per_layer"], r)
+
+
+def test_run_reduces_a_traced_run_with_trace_scopes(monkeypatch, tmp_path):
+    """``Tracer.reduce`` loads the profiler's file with ``trace_scopes``;
+    on the chip fixture that feeds the four scoped metrics, and the six
+    listed before them read what ``trace_reduce`` gave them."""
+    from bench import run
+
+    with open(SCOPED) as f:
+        trace = json.load(f)
+    tracer = run.Tracer(None, 10.0)
+    profile = os.path.join(tracer.dir, "plugins", "profile", "1")
+    os.makedirs(profile)
+    open(os.path.join(profile, "host.xplane.pb"), "wb").close()
+    loaded = []
+    monkeypatch.setattr(trace_scopes, "load",
+                        lambda path, scopes: loaded.append((path, scopes))
+                        or trace)
+    summary = tracer.reduce(archs.scopes(run.load_cell(
+        "bitnet-0.73b.edge")["cfg"]))
+    assert loaded and loaded[0][0].endswith("host.xplane.pb")
+    assert loaded[0][1] == trace_scopes.SCOPES
+    assert not os.path.exists(tracer.dir)
+
+    got = traced_metrics(summary)
+    before = traced_metrics(trace_reduce.reduce(trace))
+    assert set(before) <= set(LISTED)
+    assert {m: got[m] for m in before} == before  # exactly, bit for bit
+    assert set(got) == set(before) | set(SCOPED_METRICS)
+    for m in SCOPED_METRICS:
+        assert got[m]["unit"] == "ms" and got[m]["value"] > 0, m
+    tick = got["decode_tick_ms"]["value"]
+    assert sum(got[m]["value"] for m in SCOPED_METRICS[:3]) < tick
+
+    breakdown = run.trace_breakdown(summary)
+    assert set(breakdown) == {"device_ops", "idle_gaps", "device_scopes"}
+    assert breakdown["device_scopes"] == [
+        list(x) for x in trace_scopes.top_scopes(summary["scopes"])]
+    assert breakdown["device_scopes"][0][0].startswith("_decode_block_dev/")
+    assert all(len(v) <= 10 for v in breakdown.values())

@@ -46,13 +46,15 @@ WAVE_SPAN, WAVE_PROGRAM = "engine.wave", "_prefill_chunks"
 DECODE_BLOCK = "_decode_block_dev"
 HOST_SPANS = ("bench.", "engine.")
 # the name scopes of the served programs (``jax.named_scope`` in the model
-# and the engine); an op belongs to the innermost one on its path
+# and the engine); an op belongs to the innermost one on its path.  An
+# architecture whose programs carry scopes of their own adds them
+# (``bench.archs.scopes``), and passes the whole tuple as ``scopes``.
 SCOPES = ("predecode", "embed", "qkv", "attention", "o_proj", "gate_up",
           "down", "lm_head", "sample")
 UNSCOPED = "unscoped"
 
 
-def load(path: str) -> dict:
+def load(path: str, scopes: tuple = SCOPES) -> dict:
     from jax.profiler import ProfileData
 
     trace = trace_reduce.load(path)
@@ -65,15 +67,15 @@ def load(path: str) -> dict:
                         host.append((e.name, e.start_ns, e.duration_ns))
                         meta.append({k: str(v) for k, v in e.stats})
     with open(path, "rb") as f:
-        paths = hlo_op_paths(f.read())
+        paths = hlo_op_paths(f.read(), scopes)
     return {"devices": trace["devices"], "host": host, "host_meta": meta,
             "op_paths": _paths_of_ops_run(trace["devices"], paths)}
 
 
-def scope_of(path: str) -> str:
-    """The innermost of ``SCOPES`` on a name-scope path."""
+def scope_of(path: str, scopes: tuple = SCOPES) -> str:
+    """The innermost of ``scopes`` on a name-scope path."""
     for part in reversed(path.split("/")):
-        if part in SCOPES:
+        if part in scopes:
             return part
     return UNSCOPED
 
@@ -133,7 +135,7 @@ def _ints(value) -> list:
     return out
 
 
-def _instruction_paths(module) -> dict:
+def _instruction_paths(module, scopes: tuple) -> dict:
     """Instruction name -> name-scope path in one ``HloModuleProto``.
 
     A fusion carries the path of its fused root, which may lie outside
@@ -177,9 +179,10 @@ def _instruction_paths(module) -> dict:
     for iid, (name, opcode, path, called, _) in insts.items():
         if opcode == "fusion":
             inner = [m for c in called for m in comps.get(c, ())
-                     if scope_of(m[2]) != UNSCOPED]
+                     if scope_of(m[2], scopes) != UNSCOPED]
             pick = ([m[2] for m in inner if m[1] in heavy]
-                    + ([path] if scope_of(path) != UNSCOPED else [])
+                    + ([path] if scope_of(path, scopes) != UNSCOPED
+                       else [])
                     + [m[2] for m in inner])
             path = pick[0] if pick else path
         paths[iid] = path
@@ -195,7 +198,7 @@ def _instruction_paths(module) -> dict:
     return out
 
 
-def hlo_op_paths(xspace: bytes) -> dict:
+def hlo_op_paths(xspace: bytes, scopes: tuple = SCOPES) -> dict:
     """Program event name (``jit_f(123)``) -> instruction -> name-scope
     path, for every compiled program that the profiler stored in the
     ``/host:metadata`` plane of a serialized ``XSpace``."""
@@ -220,7 +223,7 @@ def hlo_op_paths(xspace: bytes) -> dict:
             for proto in protos:
                 for k, module in _fields(proto):
                     if k == 1:
-                        out[name] = _instruction_paths(module)
+                        out[name] = _instruction_paths(module, scopes)
     return out
 
 
@@ -337,12 +340,12 @@ def _waves(trace, lo, hi) -> list:
     return out
 
 
-def reduce(trace: dict, top: int = 10) -> dict:
+def reduce(trace: dict, top: int = 10, scopes: tuple = SCOPES) -> dict:
     out = trace_reduce.reduce(trace, top)
     lo, hi = trace_reduce.window(trace)
     op_paths = trace.get("op_paths", {})
     ops: dict = {}
-    scopes: dict = {}
+    by_scope: dict = {}
     for dev in trace["devices"]:
         counted = {(m[0], m[1]) for m in dev["modules"] if lo <= m[1] < hi}
         mods, starts = _executions(dev)
@@ -351,13 +354,13 @@ def reduce(trace: dict, top: int = 10) -> dict:
                 ops[name] = ops.get(name, 0.0) + own_w * 1e-9
             m = _module_of(mods, starts, start)
             if m is not None and (m[0], m[1]) in counted:
-                sc = scope_of(op_paths.get(m[0], {}).get(name, ""))
-                by = scopes.setdefault(module_name(m[0]), {})
+                sc = scope_of(op_paths.get(m[0], {}).get(name, ""), scopes)
+                by = by_scope.setdefault(module_name(m[0]), {})
                 by[sc] = by.get(sc, 0.0) + own * 1e-9
     out.update({
         "device_ops": sorted(ops.items(), key=lambda kv: -kv[1])[:top],
         "idle_gaps": _gaps(trace, lo, hi, top),
-        "scopes": scopes,
+        "scopes": by_scope,
         "waves": _waves(trace, lo, hi),
     })
     return out

@@ -6,7 +6,8 @@ drawn in one jitted call in the type it is served in: base-3 codes as
 uint8, scales and gains as float32, the embedding and an untied head as
 float32.  Codes are uniform over the 3**5 codes of five ternary weights;
 rows past a matrix's fan-in, which the layout pads, hold the code of five
-zero weights.
+zero weights.  The fan-ins, and any leaf these rules do not cover, are the
+configuration's architecture's (``bench/archs``).
 """
 
 from __future__ import annotations
@@ -14,33 +15,20 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from bench.archs import arch
 from repro.configs.base import ModelConfig
 from repro.models import transformer
 
 
 def model_config(cfg: dict) -> ModelConfig:
     """The program's config object for a ``bench/configs`` file."""
-    return ModelConfig(
-        name=cfg["name"], family="dense", block_kind="attn",
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        tie_embeddings=cfg["tie_word_embeddings"],
-        rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"],
-        group_size=cfg["pack_group"])
+    return arch(cfg).model_config(cfg)
 
 
 def seed_key(seed: int) -> jax.Array:
     """A PRNG key from any non-negative seed, also one past 32 bits."""
     return jax.random.fold_in(jax.random.PRNGKey(seed & 0xFFFFFFFF),
                               (seed >> 32) & 0xFFFFFFFF)
-
-
-def fan_in(cfg: dict) -> dict:
-    d, q = cfg["hidden_size"], cfg["num_attention_heads"] * cfg["head_dim"]
-    return {"q": d, "k": d, "v": d, "o": q, "gate": d, "up": d,
-            "down": cfg["intermediate_size"]}
 
 
 def _codes(key, shape, n_in: int, group: int):
@@ -53,16 +41,24 @@ def _codes(key, shape, n_in: int, group: int):
     return codes.astype(jnp.uint8)
 
 
+def _is_norm(owner) -> bool:
+    """The program names a norm's owner ``ln*`` or ``*norm``."""
+    return str(owner).startswith("ln") or str(owner).endswith("norm")
+
+
 def _leaf(cfg: dict, path: tuple, shape, dtype, key):
+    """One served leaf by the rules every architecture shares; a leaf they
+    do not cover goes to the architecture's own ``leaf``, where it has
+    one."""
     names = [getattr(p, "key", None) for p in path]
     leaf, owner = names[-1], names[-2]
-    fans = fan_in(cfg)
+    mod = arch(cfg)
     if leaf == "codes":
-        return _codes(key, shape, fans[owner], cfg["pack_group"])
+        return _codes(key, shape, mod.fan_in(cfg)[owner], cfg["pack_group"])
     if leaf == "gamma":
-        base = 0.8 / jnp.sqrt(jnp.float32(fans[owner]))
+        base = 0.8 / jnp.sqrt(jnp.float32(mod.fan_in(cfg)[owner]))
         return base * jax.random.uniform(key, shape, jnp.float32, 0.9, 1.1)
-    if leaf == "w" and owner in ("ln1", "ln2", "final_norm"):
+    if leaf == "w" and _is_norm(owner):
         return jnp.ones(shape, dtype)
     if leaf == "tok":
         return (0.02 * jax.random.normal(key, shape, jnp.float32)
@@ -72,7 +68,11 @@ def _leaf(cfg: dict, path: tuple, shape, dtype, key):
                 / jnp.sqrt(jnp.float32(shape[0]))).astype(dtype)
     if leaf == "b":
         return jnp.zeros(shape, dtype)
-    raise ValueError(f"no rule to draw weight {names}")
+    rule = getattr(mod, "leaf", None)
+    value = rule(names, shape, dtype, key) if rule else None
+    if value is None:
+        raise ValueError(f"no rule to draw weight {names}")
+    return value
 
 
 def served_shapes(cfg: dict):
